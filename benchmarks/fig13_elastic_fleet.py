@@ -30,11 +30,12 @@ embeds the fleet's merged registry snapshot plus the victim's recovered
 timeline tail.
 
 The bench drives the whole scenario through the unified serving API
-(``ServeConfig`` + ``Serve.local`` for the reference run, ``Serve.fleet``
-for the fleet under test) and only fires the kill once the victim
-worker's post-admission ``kind="epoch"`` marker is visible on the
-board, so the scenario exercises checkpoint-based recovery, not just
-frontend replay.
+(``ServeConfig`` + ``Serve.local`` for the reference run, which runs
+after the fleet has stopped so the parent never holds a device its
+workers need, ``Serve.fleet`` for the fleet under test) and only fires
+the kill once the victim worker's post-admission ``kind="epoch"``
+marker is visible on the board, so the scenario exercises
+checkpoint-based recovery, not just frontend replay.
 
   PYTHONPATH=src python -m benchmarks.fig13_elastic_fleet [--smoke]
 
@@ -120,7 +121,6 @@ def measure_elastic(tmp: Path, n_workers: int, n_streams: int,
     cfg = _config()
     rng = np.random.default_rng(13)
     prompts = _prompts(n_streams, rng)
-    ref = reference_tokens(cfg, prompts, max_new)
 
     root = tmp / "elastic"
     root.mkdir(parents=True, exist_ok=True)
@@ -198,6 +198,10 @@ def measure_elastic(tmp: Path, n_workers: int, n_streams: int,
         gc = fe.gc_shared(ttl_s=0.0)
     finally:
         fe.stop()
+    # the oracle runs in this process only once the workers are gone: on
+    # a chip, a parent that touched JAX first would hold the device the
+    # workers need
+    ref = reference_tokens(cfg, prompts, max_new)
 
     # (a) token identity, migrated and surviving streams alike
     mismatches = [i for i, r in enumerate(rids) if outs[r] != ref[i]]

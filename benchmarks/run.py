@@ -47,6 +47,9 @@ def modules():
 
 
 def main() -> None:
+    from repro.launch.chip import enable_compile_cache
+
+    enable_compile_cache()
     mods = modules()
     selected = sys.argv[1:] or list(mods)
     print("name,us_per_call,derived")

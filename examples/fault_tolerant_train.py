@@ -19,6 +19,7 @@ from repro.cluster.topology import VirtualCluster
 from repro.configs import get_config
 from repro.core.scr import SCRManager, Strategy
 from repro.data.pipeline import TokenPipeline
+from repro.launch.chip import enable_compile_cache
 from repro.memory.stack import TierStack
 from repro.models.registry import get_model
 from repro.optim.adamw import AdamWConfig
@@ -40,6 +41,7 @@ def build_cfg(hundred_m: bool):
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--hundred-m", action="store_true")
     ap.add_argument("--steps", type=int, default=None)

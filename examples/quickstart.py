@@ -19,6 +19,7 @@ from repro.cluster.topology import VirtualCluster
 from repro.configs import get_config
 from repro.core.scr import SCRManager, Strategy
 from repro.data.pipeline import TokenPipeline
+from repro.launch.chip import enable_compile_cache
 from repro.memory.stack import TierStack
 from repro.models.registry import get_model
 from repro.optim.adamw import AdamWConfig
@@ -26,6 +27,7 @@ from repro.train.trainer import FailureEvent, Trainer
 
 
 def main():
+    enable_compile_cache()
     cfg = get_config("phi3-mini-3.8b").reduced()
     model = get_model(cfg)
     root = Path(tempfile.mkdtemp(prefix="deeper_quickstart_"))

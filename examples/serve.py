@@ -36,10 +36,12 @@ import numpy as np
 from repro.api import ResilienceSession
 from repro.cluster.topology import VirtualCluster
 from repro.core.scr import Strategy
+from repro.launch.chip import enable_compile_cache
 from repro.serve import Serve, ServeConfig
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm3-4b")
     ap.add_argument("--steps", type=int, default=8,

@@ -22,6 +22,7 @@ import numpy as np
 from repro.cluster.topology import Module, NodeState, VirtualCluster
 from repro.core.offload import OffloadEngine, split_mesh
 from repro.core.tasks import TaskRuntime
+from repro.launch.chip import enable_compile_cache
 from repro.memory.tiers import MemoryHierarchy
 
 GRID = 64          # field grid cells
@@ -47,6 +48,7 @@ def particle_push(pos, vel, e_field):
 
 
 def main():
+    enable_compile_cache()
     # Cluster-Booster split of the device grid (1 CPU device here, but the
     # same split works on any mesh — see tests/test_offload.py on 8 devs)
     devs = np.array(jax.devices()[:1]).reshape(1, 1)

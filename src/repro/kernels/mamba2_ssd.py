@@ -24,6 +24,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 64
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _ssd_kernel(dtx_ref, adt_ref, b_ref, c_ref, y_ref, s_out_ref, state_scr,
@@ -45,16 +46,32 @@ def _ssd_kernel(dtx_ref, adt_ref, b_ref, c_ref, y_ref, s_out_ref, state_scr,
     brow = ci * chunk + jax.lax.broadcasted_iota(jnp.int32, bm.shape, 0)
     bm = jnp.where(brow < t, bm, 0.0)
 
-    L = jnp.cumsum(adt[:, :1], axis=0)      # (C, 1)  running log-decay
+    c = adt.shape[0]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # running log-decay as MXU prefix-sum matmuls (Mosaic has no
+    # cumsum): L as a (C, 1) column and, contracted the other way, as a
+    # (1, C) row, so e^{L_i - L_j} needs no transpose
+    tril = jnp.where(jj <= ii, 1.0, 0.0)
+    a1 = adt[:, :1]                          # (C, 1)
+    L = jax.lax.dot_general(tril, a1, (((1,), (0,)), ((), ())),
+                            precision=_EXACT,
+                            preferred_element_type=jnp.float32)  # (C, 1)
+    L_row = jax.lax.dot_general(a1, tril, (((0,), (1,)), ((), ())),
+                                precision=_EXACT,
+                                preferred_element_type=jnp.float32)  # (1, C)
     Ltot = L[-1:, :]                         # (1, 1)
+    # the chunk total once more as a (P, 1) column: scaling S by a (1, 1)
+    # would broadcast over sublanes and lanes at once, which Mosaic lacks
+    Ltot_col = jax.lax.dot_general(adt, jnp.ones((c, 1), jnp.float32),
+                                   (((0,), (0,)), ((), ())),
+                                   precision=_EXACT,
+                                   preferred_element_type=jnp.float32)
 
     S = state_scr[...]                       # (P, N)
     G = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)  # (C, C)
-    c = G.shape[0]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    D = jnp.exp(L - L.T)                     # e^{L_i - L_j}; masked below
+    D = jnp.exp(L - L_row)                   # e^{L_i - L_j}; masked below
     A = jnp.where(jj <= ii, G * D, 0.0)
     y = jax.lax.dot_general(A, dtx, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (C, P)
@@ -64,7 +81,7 @@ def _ssd_kernel(dtx_ref, adt_ref, b_ref, c_ref, y_ref, s_out_ref, state_scr,
     y_ref[0] = y.astype(y_ref.dtype)
 
     bdec = bm * jnp.exp(Ltot - L)
-    S = jnp.exp(Ltot) * S + jax.lax.dot_general(
+    S = jnp.exp(Ltot_col) * S + jax.lax.dot_general(
         dtx, bdec, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     state_scr[...] = S

@@ -29,6 +29,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 CHUNK = 16
+_EXACT = jax.lax.Precision.HIGHEST
 
 
 def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, state_scr,
@@ -51,9 +52,19 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, state_scr,
     v = jnp.where(rows < t, v, jnp.zeros_like(v))
 
     logw = jnp.log(jnp.maximum(w, 1e-38))
-    L = jnp.cumsum(logw, axis=0)          # (C, D)
+    c = logw.shape[0]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    # chunk-local prefix sums as MXU matmuls (Mosaic has no cumsum):
+    # L_i = sum_{j<=i} logw_j, and the chunk total as a (D, 1) column
+    L = jax.lax.dot_general(jnp.where(jj <= ii, 1.0, 0.0), logw,
+                            (((1,), (0,)), ((), ())), precision=_EXACT,
+                            preferred_element_type=jnp.float32)  # (C, D)
     Lp = L - logw                          # L_{i-1}
     Ltot = L[-1:, :]                       # (1, D)
+    Ltot_col = jax.lax.dot_general(logw, jnp.ones((c, 1), jnp.float32),
+                                   (((0,), (0,)), ((), ())), precision=_EXACT,
+                                   preferred_element_type=jnp.float32)
 
     r_dec = r * jnp.exp(Lp)                          # for inter-chunk term
     r_ctr = r * jnp.exp(Lp - Ltot)                   # re-centered (<= 0 exp)
@@ -62,9 +73,6 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, state_scr,
     S = state_scr[...]                               # (D, D)
     A = jax.lax.dot_general(r_ctr, k_ctr, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # (C, C)
-    c = A.shape[0]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
     A = jnp.where(jj < ii, A, 0.0)                   # strict lower triangle
     y = jax.lax.dot_general(A, v, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -74,7 +82,7 @@ def _wkv6_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, y_ref, s_out_ref, state_scr,
                                 preferred_element_type=jnp.float32)
     y_ref[0] = y.astype(y_ref.dtype)
 
-    S = jnp.exp(Ltot).T * S + jax.lax.dot_general(
+    S = jnp.exp(Ltot_col) * S + jax.lax.dot_general(
         k_ctr, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     state_scr[...] = S

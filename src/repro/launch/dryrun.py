@@ -306,9 +306,6 @@ def _lower_one(cfg, shape, mesh, micro_batches=1):
 
 def _metrics(compiled):
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        # jax >= 0.4.31 returns a per-executable list of property dicts
-        cost = cost[0] if cost else {}
     return {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),

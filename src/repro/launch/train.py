@@ -25,12 +25,14 @@ from repro.cluster.topology import NodeState, VirtualCluster
 from repro.configs import get_config
 from repro.core.scr import Strategy
 from repro.data.pipeline import TokenPipeline
+from repro.launch.chip import enable_compile_cache
 from repro.models.registry import get_model
 from repro.optim.adamw import AdamWConfig
 from repro.train.trainer import FailureEvent, Trainer
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--steps", type=int, default=50)

@@ -16,6 +16,7 @@ Attention comes in three exact variants:
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -43,6 +44,16 @@ class LeafSpec:
 ParamTable = Dict[str, Any]  # nested dict of LeafSpec
 
 
+@functools.partial(jax.jit, static_argnums=(1, 3))
+def _scaled_normal(key: jax.Array, shape, scale: float, dtype) -> jax.Array:
+    # one program per leaf: eagerly, the float32 draw and its scaled copy
+    # would both sit in device memory beside the weights built so far.
+    # The barrier keeps XLA from folding ``scale`` into the draw's own
+    # constant factor, which would round differently from the eager form
+    draw = jax.lax.optimization_barrier(jax.random.normal(key, shape))
+    return (draw * scale).astype(dtype)
+
+
 def _init_leaf(key: jax.Array, spec: LeafSpec, dtype) -> jax.Array:
     if spec.init == "zeros":
         return jnp.zeros(spec.shape, dtype)
@@ -52,7 +63,7 @@ def _init_leaf(key: jax.Array, spec: LeafSpec, dtype) -> jax.Array:
     scale = spec.scale if spec.scale is not None else 1.0 / math.sqrt(fan_in)
     if spec.init == "embed":
         scale = spec.scale if spec.scale is not None else 0.02
-    return (jax.random.normal(key, spec.shape) * scale).astype(dtype)
+    return _scaled_normal(key, tuple(spec.shape), scale, jnp.dtype(dtype))
 
 
 def materialize(key: jax.Array, table: ParamTable, dtype=jnp.float32) -> Any:

@@ -26,16 +26,20 @@ import dataclasses
 import tempfile
 from dataclasses import dataclass
 from pathlib import Path
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
+
+from repro.configs.base import ArchConfig
 
 
 @dataclass(frozen=True)
 class ServeConfig:
     """Everything needed to build a serving stack, local or fleet.
 
-    Model side: ``arch`` names a registry config (built ``reduced()``
-    unless ``full_size``); ``seed`` is the params seed (fleet workers
-    must share it — migration correctness rests on identical params).
+    Model side: ``arch`` is a registry name or an
+    :class:`~repro.configs.base.ArchConfig` (built ``reduced()`` unless
+    ``full_size``; its ``param_dtype`` is the dtype the weights are held
+    in); ``seed`` is the params seed (fleet workers must share it —
+    migration correctness rests on identical params).
 
     Scheduler side: ``paged`` picks the in-jit page-pool decode loop
     (``PagedServeScheduler``) over the contiguous lane path; ``spec_k``
@@ -54,7 +58,7 @@ class ServeConfig:
     ``hb_interval_s`` / ``hb_timeout_s`` pace the failure detector, and
     ``adopt_batch`` > 0 throttles per-admission board adoption."""
 
-    arch: str = "phi3-mini-3.8b"
+    arch: Union[str, ArchConfig] = "phi3-mini-3.8b"
     seed: int = 0
     full_size: bool = False
     # scheduler
@@ -77,12 +81,24 @@ class ServeConfig:
     hb_timeout_s: float = 2.0
     adopt_batch: int = 0
 
-    def worker_spec(self, shared_root: str, name: str = "") -> Any:
-        """The per-worker spawn spec this config denotes."""
+    def resolved_arch(self) -> ArchConfig:
+        """The architecture this config serves, cut to ``reduced()``
+        unless ``full_size``."""
+        from repro.configs import get_config
+
+        arch = get_config(self.arch) if isinstance(self.arch, str) \
+            else self.arch
+        return arch if self.full_size else arch.reduced()
+
+    def worker_spec(self, shared_root: str, name: str = "",
+                    chip: Optional[int] = None) -> Any:
+        """The per-worker spawn spec this config denotes; ``chip`` is the
+        host chip the worker is confined to (``None``: no confinement)."""
         from repro.serve.fleet.worker import WorkerSpec
 
         return WorkerSpec(
-            shared_root=str(shared_root), arch=self.arch, slots=self.slots,
+            shared_root=str(shared_root), arch=self.resolved_arch(),
+            chip=chip, slots=self.slots,
             max_len=self.max_len, page_tokens=self.page_tokens,
             quantum=self.quantum, pool_pages=self.pool_pages,
             spec_k=self.spec_k,
@@ -96,12 +112,9 @@ class ServeConfig:
 def _build_model(cfg: ServeConfig) -> Tuple[Any, Any, Any]:
     import jax
 
-    from repro.configs import get_config
     from repro.models.registry import get_model
 
-    arch = get_config(cfg.arch)
-    if not cfg.full_size:
-        arch = arch.reduced()
+    arch = cfg.resolved_arch()
     model = get_model(arch)
     params = model.init(jax.random.PRNGKey(cfg.seed), arch)
     return arch, model, params
@@ -207,14 +220,17 @@ class Serve:
         workers inherit the epoch-checkpoint cadence, so a fleet built
         here is elastic out of the box when ``cfg.ckpt_every`` > 0.
         ``shared_root`` defaults to a fresh temp dir (use an explicit
-        path to join an existing domain)."""
+        path to join an existing domain).  Worker ``i`` is confined to
+        chip ``i`` of the host, so a fleet on TPU has at most one worker
+        per chip; the frontend itself never touches JAX."""
         if workers < 1:
             raise ValueError("need at least one worker")
         if shared_root is None:
             shared_root = tempfile.mkdtemp(prefix="deeper_fleet_")
         from repro.serve.fleet.frontend import FleetFrontend
 
-        specs = [cfg.worker_spec(shared_root, name=f"w{i}")
+        # worker i holds chip i of the host: one process per chip
+        specs = [cfg.worker_spec(shared_root, name=f"w{i}", chip=i)
                  for i in range(workers)]
         kw = dict(frontend_kw)
         kw.setdefault("hb_timeout_s", cfg.hb_timeout_s)

@@ -17,7 +17,7 @@ Protocol (dicts over a ``multiprocessing.Pipe``), parent -> worker::
 
 worker -> parent::
 
-    {"op": "ready", "pid"}                  once, after jit warm-up
+    {"op": "ready", "pid", "arch", "device"}  once, after the model build
     {"op": "hb", "pid", "step"}             liveness heartbeat (periodic)
     {"op": "tokens", "rid", "tokens"}       incremental decode output
     {"op": "done", "rid", "tokens"}         full output, stream finished
@@ -56,11 +56,14 @@ a peer's page read-through-promotes into the local fast tier.
 from __future__ import annotations
 
 import multiprocessing as mp
+import os
+import threading
 from collections import deque
 from dataclasses import dataclass, field
 from pathlib import Path
 from typing import Any, Deque, Dict, List, Optional
 
+from repro.configs.base import ArchConfig
 from repro.memory.tiers import CapacityError
 
 
@@ -74,6 +77,12 @@ class WorkerSpec:
     continue a dead peer's stream because both run the same params and
     greedy decode is a pure function of token history).
 
+    ``arch`` is the resolved architecture the worker builds, exactly as
+    given (``ServeConfig.worker_spec`` resolves registry names and the
+    ``reduced()`` cut).  ``chip`` confines the worker to that chip of
+    the host through the libtpu environment it is spawned with (one
+    process per chip); ``None`` leaves the environment as it is.
+
     ``name`` is the worker's fleet-unique identity (``FleetFrontend``
     assigns ``w<i>`` when empty); it namespaces the worker's epoch
     checkpoint domain.  ``ckpt_every`` > 0 enables the periodic epoch
@@ -83,7 +92,8 @@ class WorkerSpec:
     adopts (the large-fleet throttle)."""
 
     shared_root: str
-    arch: str = "phi3-mini-3.8b"
+    arch: ArchConfig
+    chip: Optional[int] = None
     slots: int = 2
     max_len: int = 32
     page_tokens: int = 4
@@ -101,6 +111,10 @@ class WorkerSpec:
     adopt_batch: int = 0
 
 
+# launches briefly edit os.environ; concurrent launches must not mix
+_LAUNCH_LOCK = threading.Lock()
+
+
 def epoch_domain(worker_name: str) -> str:
     """The per-worker checkpoint namespace under the shared root."""
     return f"scr-{worker_name or 'w'}"
@@ -111,7 +125,6 @@ def _build_scheduler(spec: WorkerSpec):
     # spawn target) without paying for jax/model state
     import jax
 
-    from repro.configs import get_config
     from repro.memory.shared import SharedTier
     from repro.models.registry import get_model
     from repro.serve.kvpage import KVPager
@@ -121,7 +134,7 @@ def _build_scheduler(spec: WorkerSpec):
     from repro.obs.metrics import Registry
     from repro.obs.trace import Tracer
 
-    cfg = get_config(spec.arch).reduced()
+    cfg = spec.arch
     model = get_model(cfg)
     params = model.init(jax.random.PRNGKey(spec.seed), cfg)
     # one registry spans the worker's whole stack (tier/kv/sched/shared
@@ -188,8 +201,6 @@ def save_epoch(sess, sched, rid_of: Dict[int, Any], step: int) -> int:
     variable-size facts (rids, shapes) in the descriptor's JSON meta,
     so the frontend can restore with zero prior knowledge of the
     stream set.  Returns the number of streams checkpointed."""
-    import os
-
     import numpy as np
 
     descs = [d for d in sched.live_descriptors()
@@ -255,12 +266,15 @@ def load_epoch(shared_root, worker_name: str) -> Dict[Any, Dict[str, Any]]:
 
 def worker_main(conn, spec: WorkerSpec) -> None:
     """Entry point of a spawned worker process."""
-    import os
     import time
 
+    import jax
+
+    from repro.launch.chip import enable_compile_cache
     from repro.obs.recorder import FlightRecorder
     from repro.serve.fleet.board import PrefixBoard, record_kind
 
+    enable_compile_cache()
     sched, pager, prefix, shared = _build_scheduler(spec)
     # black box: every completed span/event lands in the recorder; the
     # heartbeat tick flushes it append-only through the shared tier so
@@ -278,7 +292,11 @@ def worker_main(conn, spec: WorkerSpec) -> None:
             spec.shared_root, domain=epoch_domain(spec.name))
         sess.tracer = sched.tracer      # ckpt_txn spans reach the black box
     pid = os.getpid()
-    conn.send({"op": "ready", "pid": pid})
+    dev = jax.devices()[0]
+    conn.send({"op": "ready", "pid": pid, "arch": sched.cfg, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(jax.devices()), "coords": getattr(dev, "coords", None),
+        "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS")}})
     running = True
     last_hb = 0.0
     last_ckpt_step = 0
@@ -417,6 +435,10 @@ class WorkerHandle:
         self.spec = spec
         self.inbox: Deque[Dict[str, Any]] = deque()
         self.ready = False
+        # what the worker reported once built: the ArchConfig it serves
+        # and the device it holds
+        self.arch: Optional[ArchConfig] = None
+        self.device: Optional[Dict[str, Any]] = None
         # liveness: any received message refreshes this (heartbeats are
         # just the guaranteed minimum traffic)
         self.last_hb = time.monotonic()
@@ -447,7 +469,23 @@ class WorkerHandle:
         parent, child = ctx.Pipe()
         proc = ctx.Process(target=worker_main, args=(child, spec),
                            daemon=True)
-        proc.start()
+        env = {}
+        if spec.chip is not None:
+            from repro.launch.chip import free_port, one_chip_env
+            env = one_chip_env(spec.chip, free_port())
+        # the child inherits the environment at spawn, so libtpu sees
+        # its chip before the child imports anything
+        with _LAUNCH_LOCK:
+            saved = {k: os.environ.get(k) for k in env}
+            os.environ.update(env)
+            try:
+                proc.start()
+            finally:
+                for k, v in saved.items():
+                    if v is None:
+                        os.environ.pop(k, None)
+                    else:
+                        os.environ[k] = v
         child.close()
         return cls(proc, parent, spec)
 
@@ -464,6 +502,8 @@ class WorkerHandle:
                 f"{self.proc.exitcode})") from None
         if msg.get("op") != "ready":
             raise RuntimeError(f"expected ready, got {msg!r}")
+        self.arch = msg.get("arch")
+        self.device = msg.get("device")
         self.ready = True
         self._saw_traffic()
 

@@ -11,6 +11,7 @@ import time
 import numpy as np
 import pytest
 
+from repro.configs import get_config
 from repro.memory.codecs import CodecRule, make_codec
 from repro.memory.shared import SharedTier
 from repro.memory.stack import HitRatePromotion, KeyClass, TierStack
@@ -404,8 +405,9 @@ def test_cross_worker_prefix_reuse(tmp_path):
     tier, and skips the prefill — the tentpole acceptance criterion."""
     from repro.serve.fleet import WorkerHandle, WorkerSpec
 
-    mk = lambda: WorkerSpec(shared_root=str(tmp_path), slots=2, max_len=32,
-                            page_tokens=4, quantum=3)
+    arch = get_config("phi3-mini-3.8b").reduced()
+    mk = lambda: WorkerSpec(shared_root=str(tmp_path), arch=arch, slots=2,
+                            max_len=32, page_tokens=4, quantum=3)
     a, b = WorkerHandle.launch(mk()), WorkerHandle.launch(mk())
     try:
         a.wait_ready()
@@ -431,8 +433,10 @@ def test_cross_worker_prefix_reuse(tmp_path):
 def test_fleet_frontend_end_to_end(tmp_path):
     from repro.serve.fleet import FleetFrontend, TenantQuota, WorkerSpec
 
-    specs = [WorkerSpec(shared_root=str(tmp_path), slots=2, max_len=32,
-                        page_tokens=4, quantum=3) for _ in range(2)]
+    arch = get_config("phi3-mini-3.8b").reduced()
+    specs = [WorkerSpec(shared_root=str(tmp_path), arch=arch, slots=2,
+                        max_len=32, page_tokens=4, quantum=3)
+             for _ in range(2)]
     rng = np.random.default_rng(7)
     sysp = rng.integers(0, 1000, size=9).tolist()
     with FleetFrontend.launch(specs,
@@ -446,3 +450,25 @@ def test_fleet_frontend_end_to_end(tmp_path):
         assert all(len(o) == 4 for o in outs)
         assert fe.stats["completed"] == 4
         assert fe.stats["throttle_events"] >= 1   # noisy went over quota
+
+
+def test_spawned_worker_serves_the_config_arch(tmp_path):
+    """A worker builds exactly the ArchConfig its ServeConfig names — a
+    caller's own config, at whatever size it is given — and reports it,
+    with the device it holds, when ready."""
+    import dataclasses
+
+    from repro.serve import ServeConfig
+    from repro.serve.fleet import WorkerHandle
+
+    arch = dataclasses.replace(get_config("phi3-mini-3.8b").reduced(),
+                               n_layers=1, param_dtype="bfloat16")
+    cfg = ServeConfig(arch=arch, full_size=True, slots=1, max_len=16,
+                      page_tokens=4)
+    w = WorkerHandle.launch(cfg.worker_spec(str(tmp_path), name="w0"))
+    try:
+        w.wait_ready(timeout=120)
+        assert w.arch == arch == cfg.resolved_arch()
+        assert w.device["count"] >= 1 and w.device["platform"]
+    finally:
+        w.stop()

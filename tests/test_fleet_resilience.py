@@ -378,6 +378,10 @@ def test_serve_config_worker_spec_carries_resilience_knobs(tmp_path):
     assert spec.slots == 3 and spec.max_len == 64
     assert spec.page_tokens == 8 and spec.seed == 5
     assert spec.shared_root == str(tmp_path)
+    # the spec carries the resolved architecture, reduced unless full_size
+    from repro.configs import get_config
+    assert spec.arch == get_config("phi3-mini-3.8b").reduced()
+    assert spec.chip is None
 
 
 def test_serve_fleet_rejects_zero_workers():

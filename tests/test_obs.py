@@ -464,7 +464,10 @@ def test_flight_recorder_survives_worker_sigkill(tmp_path):
     from repro.serve.fleet import FleetFrontend, WorkerSpec
     from repro.serve.fleet.worker import WorkerHandle
 
-    spec = WorkerSpec(shared_root=str(tmp_path), name="wkill", slots=2,
+    from repro.configs import get_config
+
+    spec = WorkerSpec(shared_root=str(tmp_path), name="wkill",
+                      arch=get_config("phi3-mini-3.8b").reduced(), slots=2,
                       max_len=64, page_tokens=4, quantum=3,
                       hb_interval_s=0.05)
     w = WorkerHandle.launch(spec)
