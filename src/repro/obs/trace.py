@@ -8,9 +8,20 @@ dicts.  The taxonomy the serving stack emits:
 span / event            where
 ======================  ======================================================
 ``submit``              request enters the scheduler / frontend (event)
+``admit``               one admission from the run queue, whole (tid: sid;
+                        args: kind fresh|resume, and for fresh: tokens
+                        prefilled, saved from the prefix cache)
 ``prefix_match``        radix-tree lookup at admission
-``prefill``             batched prompt prefill (args: tokens, saved)
-``step``                one scheduler decode step (args: resident, emitted)
+``call_prefill``        one T=8 paged-step call of an admission prefill
+                        (dispatch only)
+``step``                one scheduler decode step (args: active, emitted),
+                        tiled by its phases in the paged scheduler:
+``schedule``            step phase: ``_schedule()``, the admissions
+``feed``                step phase: build the step's tokens and positions,
+                        draft proposals
+``call_decode``         step phase: the decode-step call (dispatch only)
+``wait``                step phase: the host blocks on the step's output
+``commit``              step phase: accept tokens, finish streams
 ``park`` / ``spill``    stream KV leaves the pool / device
 ``fetch`` / ``resume``  parked stream re-admitted (args: bytes_moved)
 ``finish``              stream completes (event)
@@ -21,12 +32,20 @@ span / event            where
 ======================  ======================================================
 
 Design constraints: recording must stay off the hot path — a span is
-two ``time.perf_counter()`` calls, one small dict, and a bounded
-``deque.append``; nothing touches a device buffer or forces a host
-sync, and a disabled tracer short-circuits to a shared no-op context.
-``perf_counter`` is ``CLOCK_MONOTONIC`` on Linux — one clock across
-the fleet's processes on a host — so worker timelines interleave
-correctly in one Perfetto view.
+two ``time.perf_counter()`` calls, one small dict, one profiler
+annotation and a bounded ``deque.append``; nothing touches a device
+buffer or forces a host sync, and a disabled tracer short-circuits to a
+shared no-op context.  ``perf_counter`` is ``CLOCK_MONOTONIC`` on
+Linux — one clock across the fleet's processes on a host — so worker
+timelines interleave correctly in one Perfetto view.
+
+Every span of an enabled tracer is also a
+``jax.profiler.TraceAnnotation`` named ``serve.<span>`` (the name only;
+args stay in the ring), opened in :meth:`Tracer.begin` and closed in
+:meth:`Tracer.end`.  While a profiler session is open the spans land on
+the host plane of its trace, on the clock of the device's events, so a
+device idle gap can be named by the scheduler phase that holds it.
+Without a session an annotation records nothing.  Events get none.
 
 Export is the Chrome trace-event JSON format (``chrome://tracing`` /
 `ui.perfetto.dev <https://ui.perfetto.dev>`_): complete events
@@ -41,6 +60,8 @@ import os
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _NullSpan:
@@ -59,11 +80,13 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    __slots__ = ("_tracer", "rec")
+    __slots__ = ("_tracer", "rec", "ann")
 
-    def __init__(self, tracer: "Tracer", rec: Dict[str, Any]):
+    def __init__(self, tracer: "Tracer", rec: Dict[str, Any],
+                 ann: TraceAnnotation):
         self._tracer = tracer
         self.rec = rec
+        self.ann = ann
 
     def __enter__(self) -> "_Span":
         return self
@@ -111,19 +134,24 @@ class Tracer:
                                "ts": time.perf_counter(), "tid": int(tid)}
         if args:
             rec["args"] = args
-        return _Span(self, rec)
+        ann = TraceAnnotation("serve." + name)
+        ann.__enter__()
+        return _Span(self, rec, ann)
 
     def end(self, span: Optional[_Span], **args: Any) -> None:
-        if span is None or not self.enabled:
+        if span is None:
             return
         rec = span.rec
         rec["dur"] = time.perf_counter() - rec["ts"]
+        span.ann.__exit__(None, None, None)
+        if not self.enabled:
+            return
         if args:
             rec.setdefault("args", {}).update(args)
         self._emit(rec)
 
     def span(self, name: str, tid: int = 0, **args: Any):
-        """Context manager form: ``with tracer.span("prefill", tid=sid):``."""
+        """Context manager form: ``with tracer.span("admit", tid=sid):``."""
         if not self.enabled:
             return _NULL_SPAN
         return self.begin(name, tid=tid, **args)
@@ -194,8 +222,9 @@ _default: Optional[Tracer] = None
 
 def default_tracer() -> Tracer:
     """The process-wide tracer components fall back to when none is
-    injected.  Enabled by default — recording is off-hot-path cheap and
-    the fig10 overhead gate holds it to <= 3% tokens/s."""
+    injected.  Enabled by default: a span costs a few microseconds of
+    host time against a decode step of milliseconds; PERF.md gives the
+    cost measured on the chip, tracing on against off."""
     global _default
     if _default is None:
         _default = Tracer(process=f"pid{os.getpid()}")

@@ -43,6 +43,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import time
 from collections import deque
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -142,6 +143,8 @@ class DecodeStream:
     ran: int = 0                 # steps since last admit (quantum accounting)
     finished_step: Optional[int] = None
     quantum_weight: int = 1      # priority class: quantum multiplier
+    # perf_counter() at submit (or restore): the start of its queue wait
+    submitted_at: float = dataclasses.field(default_factory=time.perf_counter)
 
     @property
     def emitted(self) -> List[int]:
@@ -227,7 +230,8 @@ class ServeScheduler:
         self._next_sid = 0
         self.step_count = 0
         self.stats = StatsView(self.registry, "sched", {
-            "steps": 0, "joined": 0, "parked": 0, "resumed": 0,
+            "steps": 0, "joined": 0, "queue_wait_s": 0.0,
+            "parked": 0, "resumed": 0,
             "finished": 0, "park_failures": 0, "max_resident": 0,
             "prefill_calls": 0, "prefill_tokens": 0,
             "prefix_hits": 0, "prefill_tokens_saved": 0,
@@ -360,21 +364,29 @@ class ServeScheduler:
 
     # -- admit / park ------------------------------------------------------- #
 
+    def _joined(self, s: DecodeStream, admitted_at: float) -> None:
+        """Count a fresh admission and its wait in the run queue."""
+        self.stats["joined"] += 1
+        self.stats["queue_wait_s"] += admitted_at - s.submitted_at
+
     def _admit(self, sid: int, slot: int) -> None:
         s = self.streams[sid]
-        if s.state is StreamState.PARKED:
-            assert self.pager is not None
-            # release=False retains the page table as the dirty-tracking
-            # baseline: the next park re-puts only pages that changed
-            with self.tracer.span("resume", tid=sid, slot=slot):
-                self._set_lane(slot, self.pager.fetch(sid, self._lane_template,
-                                                      release=False))
-            self.stats["resumed"] += 1
-        else:
-            with self.tracer.span("prefill", tid=sid, slot=slot,
-                                  plen=s.plen):
+        parked = s.state is StreamState.PARKED
+        with self.tracer.span("admit", tid=sid,
+                              kind="resume" if parked else "fresh"):
+            if parked:
+                assert self.pager is not None
+                # release=False retains the page table as the dirty-
+                # tracking baseline: the next park re-puts only pages
+                # that changed
+                with self.tracer.span("resume", tid=sid, slot=slot):
+                    self._set_lane(slot, self.pager.fetch(
+                        sid, self._lane_template, release=False))
+                self.stats["resumed"] += 1
+            else:
+                now = time.perf_counter()
                 self._set_lane(slot, self._prefilled_lane(s))
-            self.stats["joined"] += 1
+                self._joined(s, now)
         s.state, s.slot, s.ran = StreamState.ACTIVE, slot, 0
         self._slot_sid[slot] = sid
 
@@ -885,19 +897,21 @@ class PagedServeScheduler(ServeScheduler):
             m = min(PREFILL_BUCKET, t1 - i)
             buf = np.zeros((1, PREFILL_BUCKET), np.int32)
             buf[0, :m] = tokens[i:i + m]
-            _, self.pool.leaves = self._paged_fn(
-                self.params, self.pool.leaves, tables,
-                jnp.asarray([i], np.int32), jnp.asarray(buf))
+            with self.tracer.span("call_prefill"):
+                _, self.pool.leaves = self._paged_fn(
+                    self.params, self.pool.leaves, tables,
+                    jnp.asarray([i], np.int32), jnp.asarray(buf))
             self.stats["prefill_calls"] += 1
             self.stats["prefill_tokens"] += m
             i += m
 
-    def _admit_fresh(self, s: DecodeStream) -> List[int]:
+    def _admit_fresh(self, s: DecodeStream) -> Tuple[List[int], int]:
         """Build a joining stream's page table: pool-resident shared
         prefix pages by *reference* (zero copy, zero compute), cached-
         but-not-resident prefix pages loaded from the stack, fresh pages
         for the rest, prompt suffix prefilled in place.  All-or-nothing:
-        a CapacityError rolls every reference back."""
+        a CapacityError rolls every reference back.  Returns the table
+        and the prompt tokens the prefix cache covered."""
         pt = self.pool.page_tokens
         target = s.plen - 1        # the last prompt token runs in the slot
         table: List[int] = []
@@ -953,9 +967,7 @@ class PagedServeScheduler(ServeScheduler):
             self.prefix.acquire(s.sid, [tail_node])
             self.stats["prefill_tokens_saved"] += m
             covered = tail_node.end
-        with self.tracer.span("prefill", tid=s.sid,
-                              tokens=max(target - covered, 0), saved=covered):
-            self._paged_prefill(table, s.tokens, covered, target)
+        self._paged_prefill(table, s.tokens, covered, target)
         if self.prefix is not None and target > 0:
             upto = (target // pt) * pt
             if upto > covered:
@@ -976,36 +988,50 @@ class PagedServeScheduler(ServeScheduler):
                     payload_fn=lambda end: self.pool.read_token_range(
                         table[upto // pt], end - upto))
         s.pos = max(target, 0)
-        return table
+        return table, covered
 
     def _admit(self, sid: int, slot: int) -> None:
         s = self.streams[sid]
-        if s.state is StreamState.PARKED:
-            if self.pager is not None and self.pager.is_parked(sid):
-                # spilled: the only resume path that moves KV bytes
-                _sp = self.tracer.begin("fetch", tid=sid)
-                phys = self.pool.alloc(self.pool.pages_per_lane)
-                try:
-                    blobs = self.pager.fetch_pages(sid, release=True)
-                except Exception:
-                    for p in phys:
-                        self.pool.deref(p)
-                    raise
-                for p, b in zip(phys, blobs):
-                    self.pool.write_blob(p, b)
-                self._ptables[sid] = phys
-                moved = sum(len(b) for b in blobs)
-                self.tracer.end(_sp, bytes_moved=moved)
-                self.stats["refilled"] += 1
-                self.stats["kv_resume_bytes_moved"] += moved
-            # else: pages never left the pool — resume moves 0 KV bytes
-            self.stats["resumed"] += 1
-        else:
-            self._ptables[sid] = self._admit_fresh(s)
-            self.stats["joined"] += 1
+        parked = s.state is StreamState.PARKED
+        _sp = self.tracer.begin("admit", tid=sid,
+                                kind="resume" if parked else "fresh")
+        done: Dict[str, int] = {}
+        try:
+            if parked:
+                self._refill(sid)
+            else:
+                now = time.perf_counter()
+                self._ptables[sid], covered = self._admit_fresh(s)
+                self._joined(s, now)
+                done = {"tokens": max(s.plen - 1 - covered, 0),
+                        "saved": covered}
+        finally:
+            self.tracer.end(_sp, **done)
         s.state, s.slot, s.ran = StreamState.ACTIVE, slot, 0
         self._slot_sid[slot] = sid
         self._tables_arr[slot] = self._ptables[sid]
+
+    def _refill(self, sid: int) -> None:
+        """Resume a parked stream into the pool."""
+        if self.pager is not None and self.pager.is_parked(sid):
+            # spilled: the only resume path that moves KV bytes
+            _sp = self.tracer.begin("fetch", tid=sid)
+            phys = self.pool.alloc(self.pool.pages_per_lane)
+            try:
+                blobs = self.pager.fetch_pages(sid, release=True)
+            except Exception:
+                for p in phys:
+                    self.pool.deref(p)
+                raise
+            for p, b in zip(phys, blobs):
+                self.pool.write_blob(p, b)
+            self._ptables[sid] = phys
+            moved = sum(len(b) for b in blobs)
+            self.tracer.end(_sp, bytes_moved=moved)
+            self.stats["refilled"] += 1
+            self.stats["kv_resume_bytes_moved"] += moved
+        # else: pages never left the pool — resume moves 0 KV bytes
+        self.stats["resumed"] += 1
 
     def _drop_pool_prefix(self) -> bool:
         """Release one pool-resident prefix page held only by its digest
@@ -1162,63 +1188,74 @@ class PagedServeScheduler(ServeScheduler):
         exactly greedy semantics) commits, the rest is discarded — the
         rejected positions' KV writes land beyond the committed length
         and are overwritten by the next step's real writes.  May emit
-        several ``(sid, token)`` pairs per stream per step."""
-        _sp = self.tracer.begin("step", tid=0)
-        self._schedule()
-        active = [(slot, self.streams[sid])
-                  for slot, sid in enumerate(self._slot_sid)
-                  if sid is not None]
+        several ``(sid, token)`` pairs per stream per step.
+
+        Traced as a ``step`` span tiled by its phases: ``schedule``,
+        ``feed``, ``call_decode``, ``wait`` (the one place the host
+        blocks on the chip) and ``commit``."""
+        tr = self.tracer
+        _sp = tr.begin("step", tid=0)
+        with tr.span("schedule"):
+            self._schedule()
+            active = [(slot, self.streams[sid])
+                      for slot, sid in enumerate(self._slot_sid)
+                      if sid is not None]
         if not active:
-            self.tracer.end(_sp, active=0)
+            tr.end(_sp, active=0)
             return []
-        T = self.spec_k + 1
-        feed = np.zeros((self.slots, T), np.int32)
-        pos = np.zeros((self.slots,), np.int32)
-        known = {}
-        for slot, s in active:
-            pos[slot] = s.pos
-            k = min(T, len(s.tokens) - s.pos)
-            feed[slot, :k] = s.tokens[s.pos:s.pos + k]
-            known[s.sid] = k
-            # draft only what the commit loop can still accept: a
-            # proposal past the stream's remaining max_new budget (or
-            # the lane's max_len) finishes the stream before its row is
-            # ever verified, so proposing it only burns acceptance rate
-            want = max(0, min(T - k, s.max_new - s.n_emitted - 1,
-                              self.max_len - s.pos - k))
-            if want:
-                feed[slot, k:k + want] = self.proposer.propose(
-                    s.tokens, want)
-                self.stats["spec_proposed"] += want
-        out, self.pool.leaves = self._paged_fn(
-            self.params, self.pool.leaves, jnp.asarray(self._tables_arr),
-            jnp.asarray(pos), jnp.asarray(feed))
-        out = np.asarray(out)
-        emitted: List[Tuple[int, int]] = []
-        for slot, s in active:
-            s.ran += 1
-            accepted = 0
-            i = 0
-            while True:
-                s.pos += 1
-                if s.pos >= len(s.tokens):
-                    tok = int(out[slot, i])
-                    s.tokens.append(tok)
-                    emitted.append((s.sid, tok))
-                if s.n_emitted >= s.max_new or s.pos >= self.max_len:
-                    self._finish(s)
-                    break
-                i += 1
-                if i >= T or feed[slot, i] != s.tokens[s.pos]:
-                    break       # candidate rejected: discard the rest
-                if i >= known[s.sid]:
-                    accepted += 1
-            self.stats["spec_accepted"] += accepted
-        self.step_count += 1
-        self.stats["steps"] += 1
-        self.stats["max_resident"] = max(self.stats["max_resident"],
-                                         self.resident_streams())
-        self.tracer.end(_sp, active=len(active), emitted=len(emitted))
+        with tr.span("feed"):
+            T = self.spec_k + 1
+            feed = np.zeros((self.slots, T), np.int32)
+            pos = np.zeros((self.slots,), np.int32)
+            known = {}
+            for slot, s in active:
+                pos[slot] = s.pos
+                k = min(T, len(s.tokens) - s.pos)
+                feed[slot, :k] = s.tokens[s.pos:s.pos + k]
+                known[s.sid] = k
+                # draft only what the commit loop can still accept: a
+                # proposal past the stream's remaining max_new budget (or
+                # the lane's max_len) finishes the stream before its row
+                # is ever verified, so proposing it only burns acceptance
+                # rate
+                want = max(0, min(T - k, s.max_new - s.n_emitted - 1,
+                                  self.max_len - s.pos - k))
+                if want:
+                    feed[slot, k:k + want] = self.proposer.propose(
+                        s.tokens, want)
+                    self.stats["spec_proposed"] += want
+        with tr.span("call_decode"):
+            out, self.pool.leaves = self._paged_fn(
+                self.params, self.pool.leaves, jnp.asarray(self._tables_arr),
+                jnp.asarray(pos), jnp.asarray(feed))
+        with tr.span("wait"):
+            out = np.asarray(out)
+        with tr.span("commit"):
+            emitted: List[Tuple[int, int]] = []
+            for slot, s in active:
+                s.ran += 1
+                accepted = 0
+                i = 0
+                while True:
+                    s.pos += 1
+                    if s.pos >= len(s.tokens):
+                        tok = int(out[slot, i])
+                        s.tokens.append(tok)
+                        emitted.append((s.sid, tok))
+                    if s.n_emitted >= s.max_new or s.pos >= self.max_len:
+                        self._finish(s)
+                        break
+                    i += 1
+                    if i >= T or feed[slot, i] != s.tokens[s.pos]:
+                        break       # candidate rejected: discard the rest
+                    if i >= known[s.sid]:
+                        accepted += 1
+                self.stats["spec_accepted"] += accepted
+            self.step_count += 1
+            self.stats["steps"] += 1
+            self.stats["max_resident"] = max(self.stats["max_resident"],
+                                             self.resident_streams())
+        tr.end(_sp, active=len(active), emitted=len(emitted))
         return emitted
 
     # -- checkpoint / restore ----------------------------------------------- #

@@ -10,12 +10,15 @@ SharedTier; one slow end-to-end test SIGKILLs a real worker mid-decode
 and reconstructs its last-seconds timeline from the shared domain.
 """
 
+import glob
 import json
 import time
 from pathlib import Path
 
+import jax
 import numpy as np
 import pytest
+from jax.profiler import ProfileData
 
 from repro.memory.shared import SharedTier
 from repro.obs.metrics import (
@@ -349,6 +352,63 @@ def test_chrome_trace_export(tmp_path):
     out = tmp_path / "trace.json"
     tr.export(out)
     assert json.loads(out.read_text())["traceEvents"]
+
+
+def _profiled_serve_events(tmp_path, record):
+    """``record()`` under a CPU profiler session; the ``serve.*`` events
+    of the trace's host planes as (name, start_ns, end_ns)."""
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        record()
+    finally:
+        jax.profiler.stop_trace()
+    paths = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    assert paths, "the profiler wrote no trace"
+    out = []
+    for plane in ProfileData.from_file(paths[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("serve."):
+                    out.append((ev.name, ev.start_ns,
+                                ev.start_ns + ev.duration_ns))
+    return out
+
+
+def test_tracer_spans_land_on_the_profiler_host_plane(tmp_path):
+    tr = Tracer()
+
+    def record():
+        step = tr.begin("step")          # opened and closed in two calls
+        with tr.span("wait"):
+            time.sleep(0.002)
+        tr.event("finish")               # instants get no annotation
+        tr.end(step, active=1)
+
+    evs = {name: (s, e) for name, s, e in _profiled_serve_events(tmp_path,
+                                                                  record)}
+    assert set(evs) == {"serve.step", "serve.wait"}
+    (s0, e0), (s1, e1) = evs["serve.step"], evs["serve.wait"]
+    assert s0 <= s1 < e1 <= e0
+    assert e1 - s1 >= 2_000_000          # the sleep, in ns
+    # the ring is as it was: names, args, perf_counter durations
+    step, wait = tr.records("step")[0], tr.records("wait")[0]
+    assert step["args"] == {"active": 1} and "args" not in wait
+    assert step["ts"] <= wait["ts"] and wait["dur"] >= 0.002
+
+
+def test_disabled_tracer_emits_no_profiler_events(tmp_path):
+    off, on = Tracer(enabled=False), Tracer()
+
+    def record():
+        with off.span("step"):
+            off.end(off.begin("wait"))
+        with on.span("control"):
+            pass
+
+    evs = _profiled_serve_events(tmp_path, record)
+    assert [name for name, _, _ in evs] == ["serve.control"]
 
 
 def test_default_tracer_swap():

@@ -1,6 +1,8 @@
 """Serving subsystem: hit-rate promotion, KV paging, continuous batching,
 multi-stream kill/restore, and the failure-history checkpoint policy."""
 
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -16,8 +18,10 @@ from repro.io.serialization import serialize_state
 from repro.memory.stack import HitRatePromotion, TierStack
 from repro.memory.tiers import CapacityError, MemoryTier, TierKind, TierSpec
 from repro.models.registry import get_model
+from repro.obs.trace import Tracer
 from repro.serve.kvpage import KVPager
-from repro.serve.scheduler import ServeScheduler, StreamState
+from repro.serve.scheduler import (PagedServeScheduler, ServeScheduler,
+                                   StreamState)
 
 
 def mem_tier(capacity=10**9):
@@ -303,6 +307,70 @@ def test_oversubscribed_paged_decode_matches_reference(served_model):
         want = reference_decode(cfg, model, params, list(prompt), max_new,
                                 max_len)
         assert sched.output(sid) == want, f"stream {sid} diverged"
+    sched.close()
+
+
+def _holds(outer, inner):
+    return (outer["ts"] <= inner["ts"]
+            and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"])
+
+
+def test_paged_step_phases_tile_the_step_and_count_queue_wait(served_model):
+    """A step with an admission: step > schedule > admit > call_prefill,
+    then feed, call_decode, wait and commit in order inside the step;
+    each fresh admission adds its wait since submit to queue_wait_s."""
+    cfg, model, params = served_model
+    tr = Tracer()
+    sched = PagedServeScheduler(cfg, model, params, slots=2, max_len=24,
+                                page_tokens=4, tracer=tr)
+    sid = sched.submit(list(range(1, 11)), max_new=4)
+    sched.step()
+    spans = [r for r in tr.records() if r["ph"] == "X"]
+    # the ring holds spans in the order they end
+    assert [r["name"] for r in spans] == [
+        "call_prefill", "call_prefill", "admit", "schedule", "feed",
+        "call_decode", "wait", "commit", "step"]
+    by = {r["name"]: r for r in spans}
+    assert _holds(by["step"], by["schedule"])
+    assert _holds(by["schedule"], by["admit"])
+    assert all(_holds(by["admit"], r) for r in spans
+               if r["name"] == "call_prefill")
+    phases = [by[n] for n in ("schedule", "feed", "call_decode", "wait",
+                              "commit")]
+    for a, b in zip(phases, phases[1:]):
+        assert a["ts"] + a["dur"] <= b["ts"]
+    assert all(_holds(by["step"], r) for r in phases)
+    # 10 prompt tokens: 9 prefilled, the last runs in the slot
+    assert by["admit"]["tid"] == sid
+    assert by["admit"]["args"] == {"kind": "fresh", "tokens": 9, "saved": 0}
+    assert sched.stats["joined"] == 1
+    first = sched.stats["queue_wait_s"]
+    assert first > 0
+
+    # a plain step holds no admission; a later request's wait is counted
+    tr.clear()
+    sched.step()
+    assert [r["name"] for r in tr.records()] == [
+        "schedule", "feed", "call_decode", "wait", "commit", "step"]
+    sched.submit([3, 4, 5], max_new=2)
+    time.sleep(0.05)
+    sched.step()
+    assert sched.stats["joined"] == 2
+    assert sched.stats["queue_wait_s"] >= first + 0.05
+    sched.close()
+
+
+def test_contiguous_admission_is_one_admit_span(served_model):
+    cfg, model, params = served_model
+    tr = Tracer()
+    sched = ServeScheduler(cfg, model, params, slots=1, max_len=16,
+                           tracer=tr)
+    sid = sched.submit([5, 6, 7], max_new=2)
+    sched.step()
+    (admit,) = tr.records("admit")
+    assert admit["tid"] == sid and admit["args"] == {"kind": "fresh"}
+    assert sched.stats["joined"] == 1 and sched.stats["queue_wait_s"] > 0
+    assert tr.records("prefill") == []
     sched.close()
 
 
