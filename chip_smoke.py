@@ -27,11 +27,11 @@ Sizing (one v5e chip, 16 GB HBM; 15.75 GB usable): the weights are
 page pool holds POOL_PAGES pages of PAGE_TOKENS tokens plus a trash
 page: 2 x 32 layers x 129 x 16 x 32 x 96 x 2 B = 0.81 GB, which the
 chip lays out with the 96-wide head padded to 128 lanes: 1.08 GB.  The
-step donates nothing, so each call also writes a second pool (1.08 GB),
-and the T=8 prefill chunk keeps 1.08 GB of temporaries: by
-``memory_analysis()`` of the v5e compile, 9.81 GB for the T=1 step and
-10.89 GB for the T=8 step.  On the chip the process peaked at 13.07 GB
-in use.
+step donates nothing, so each call also writes a second pool (1.08 GB):
+by ``memory_analysis()`` of the v5e compile, 9.81 GB for the T=1 step
+and for the 128-token prefill chunk alike (the token-at-a-time T=8
+chunk it replaced kept 1.08 GB more).  On the chip the process peaked
+at 13.07 GB in use with the T=8 chunk.
 
 Earlier lines print smoke facts, not benchmark metrics.  The last line
 is one JSON object: ``{"ok": true, "device": {"platform", "kind",

@@ -302,23 +302,33 @@ def flash_attention(
 
 
 def decode_attention(
-    q: jax.Array,        # (B, Hq, D) — one new token per sequence
+    q: jax.Array,        # (B, Hq, D) or (B, T, Hq, D): new tokens' queries
     k_cache: jax.Array,  # (B, S, Hkv, D)
     v_cache: jax.Array,  # (B, S, Hkv, Dv)
-    length: jax.Array,   # (B,) valid cache lengths (including current token)
+    length: jax.Array,   # (B,) or (B, T): valid cache lengths (incl. current)
     scale: Optional[float] = None,
 ) -> jax.Array:
+    """Queries of new tokens over a padded KV cache: one token per
+    sequence, or T, where row t attends to the first ``length[b, t]``
+    cache positions.  A lane's T rows sit beside its query heads in one
+    matmul row axis."""
+    rows = q.ndim == 4
+    if not rows:
+        q, length = q[:, None], length[:, None]
     b, s, hkv, d = k_cache.shape
-    hq = q.shape[1]
+    t, hq = q.shape[1:3]
     g = hq // hkv
     scale = (d ** -0.5) if scale is None else scale
-    qg = q.reshape(b, hkv, g, d)
-    logits = jnp.einsum("bhgd,bshd->bhgs", qg * scale, k_cache).astype(jnp.float32)
-    mask = jnp.arange(s)[None, None, None, :] < length[:, None, None, None]
+    qg = q.reshape(b, t, hkv, g, d)
+    logits = jnp.einsum("bthgd,bshd->bhtgs", qg * scale,
+                        k_cache).astype(jnp.float32)
+    mask = (jnp.arange(s)[None, None, None, None, :]
+            < length[:, None, :, None, None])
     logits = jnp.where(mask, logits, _mask_value(logits.dtype))
     probs = jax.nn.softmax(logits, axis=-1)
-    out = jnp.einsum("bhgs,bshd->bhgd", probs.astype(v_cache.dtype), v_cache)
-    return out.reshape(b, hq, -1)
+    out = jnp.einsum("bhtgs,bshd->bthgd", probs.astype(v_cache.dtype), v_cache)
+    out = out.reshape(b, t, hq, -1)
+    return out if rows else out[:, 0]
 
 
 def update_cache(cache: jax.Array, new: jax.Array, pos: jax.Array) -> jax.Array:

@@ -412,6 +412,13 @@ def decode_step(
 # paged decode (pool-resident page tables)
 # ---------------------------------------------------------------------- #
 
+# The pool page that takes the K/V rows the paged step must discard: rows
+# fed PAD_TOKEN (an admission chunk's pad) and rows past a lane's last
+# slot.  Idle scheduler lanes point their whole table at it; the page
+# pool never hands it out.
+TRASH_PAGE = 0
+PAD_TOKEN = -1
+
 
 def paged_decode_step(
     params: Dict[str, Any],
@@ -426,20 +433,42 @@ def paged_decode_step(
     The KV cache never exists contiguously: reads gather through each
     lane's page-table row and writes scatter into ``pool[phys, offset]``,
     so admitting / parking / resuming a stream moves table entries, not
-    KV bytes.  ``T > 1`` is the speculative-verification mode: the T
-    inputs are [next_token, candidate_1, ..] and row t's output is the
-    greedy token *after* consuming inputs ..t — the caller commits the
-    accepted prefix.
+    KV bytes.  Row t of a lane consumes ``tokens[b, t]`` at position
+    ``pos[b] + t`` and outputs the greedy token *after* it: T = 1 is the
+    decode step, T = ``spec_k`` + 1 speculative verification (inputs
+    [next_token, candidate_1, ..], the caller commits the accepted
+    prefix), and a long T admission prefill.
 
-    Exactness contract (the property the differential oracle tests pin):
-    the T tokens run as a ``lax.scan`` whose per-token body is the same
-    computation graph as :func:`decode_step` — the only difference is
-    scatter/gather data movement, which is bit-exact — so for any T the
-    emitted tokens equal single-token contiguous greedy decode bit for
-    bit.  Positions clamp to the last cache slot; tokens fed past a
-    lane's logical end write garbage into the lane's *own* pages beyond
-    its committed length, which later real writes overwrite and the
-    length mask never reads.
+    Layer-major over the T rows: one scan over the layers carries all
+    B x T rows, so each layer's weights are read once per call and its
+    projections and FFN are ``(B*T, .)`` matmuls.  Per layer the B x T
+    new K/V rows land in the pool in one scatter, each lane's pages come
+    back in one gather ``(B, s_pad, ..)``, and row t attends to the keys
+    at positions <= ``pos[b] + t`` — its own chunk's earlier rows
+    included, later ones masked.
+
+    Exactness contract (what the differential oracle tests check):
+    every op acts on each row alone — the B x T rows only widen the row
+    axis of the matmuls, norms, softmaxes and argmax that a single-token
+    step runs (at T = 1 they are its ops) — and the scatter/gather is
+    pure data movement, so each row computes what a single-token step at
+    its position computes, up to the rounding of its matmuls.  That is
+    not bit for bit: XLA:CPU fuses a one-row matmul into its consumer as
+    an element-by-element loop and runs a matmul of two or more rows as
+    a library GEMM, and the two can differ in the last bit.  A batch-1
+    ``decode_step`` is the one-row kind; a decode step over several
+    lanes and an admission-prefill chunk are the many-row kind, so their
+    K/V and logits can differ from it in the last bit, and the emitted
+    tokens equal single-token greedy decode except where such a
+    difference decides a near-tie of the two top logits.
+
+    Rows fed ``PAD_TOKEN`` (an admission chunk's pad) or whose
+    position falls past the lane's last slot write their K/V into the
+    pool's :data:`TRASH_PAGE`, so no scatter ever holds a pad
+    row and a real row at one slot; their outputs are garbage for the
+    caller to drop.  Other rows beyond a lane's committed length
+    (rejected candidates) write into the lane's *own* pages, where later
+    real writes overwrite them and the length mask never reads them.
 
     Quantized pools (``DevicePagePool(quantized=True)``) carry an int8
     buffer per KV leaf plus a ``<leaf>__scale`` float32 companion; the
@@ -451,7 +480,8 @@ def paged_decode_step(
     Returns ``(out (B, T) int32 argmax tokens, new_pools)``.
     """
     cd = jnp.dtype(cfg.compute_dtype)
-    b, t_total = tokens.shape
+    b, t = tokens.shape
+    n = b * t                      # rows, lane-major: row b*T + t
     hq = cfg.padded_heads
     dh = cfg.resolved_head_dim
     quantized = any(k.endswith(SCALE_SUFFIX) for k in pools)
@@ -459,16 +489,25 @@ def paged_decode_step(
     page_tokens = first.shape[2]
     s_pad = tables.shape[1] * page_tokens
 
-    def pool_write_read(pool, name, new_row, phys, off):
-        """Scatter one decoded (B, *rest) row into ``pool[name]`` at
+    p_bt = pos[:, None] + jnp.arange(t, dtype=pos.dtype)[None, :]   # (B, T)
+    wp = jnp.minimum(p_bt, s_pad - 1)
+    phys = jnp.take_along_axis(tables, wp // page_tokens, axis=1)
+    phys = jnp.where((tokens == PAD_TOKEN) | (p_bt >= s_pad), TRASH_PAGE,
+                     phys)
+    phys, off = phys.reshape(n), (wp % page_tokens).reshape(n)
+    x = L.embed_tokens(params["embed"], tokens.reshape(n), cd)   # (B*T, D)
+    cos, sin = _rope_tables(cfg, p_bt.reshape(n))
+
+    def pool_write_read(pool, name, new_rows):
+        """Scatter the (B*T, *rest) new rows into ``pool[name]`` at
         [phys, off] and gather the (B, s_pad, *rest) view back through
         the tables — through the int8 + scale pair in quantized pools."""
         if not quantized:
             buf = pool[name].at[phys, off].set(
-                new_row.astype(pool[name].dtype))
+                new_rows.astype(pool[name].dtype))
             out = jnp.take(buf, tables, axis=0)
             return {name: buf}, out.reshape((b, s_pad) + out.shape[3:])
-        qv, sv = int8_quantize(new_row, axis=-1)
+        qv, sv = int8_quantize(new_rows, axis=-1)
         buf = pool[name].at[phys, off].set(qv)
         sbuf = pool[name + SCALE_SUFFIX].at[phys, off].set(sv[..., 0])
         out = int8_dequantize(jnp.take(buf, tables, axis=0),
@@ -476,90 +515,77 @@ def paged_decode_step(
         return ({name: buf, name + SCALE_SUFFIX: sbuf},
                 out.reshape((b, s_pad) + out.shape[3:]))
 
-    def one_token(pools, tk_t):
-        tok, t = tk_t                          # (B,), scalar offset in T
-        p_t = pos + t                          # (B,)
-        wp = jnp.minimum(p_t, s_pad - 1)
-        phys = jnp.take_along_axis(tables, (wp // page_tokens)[:, None],
-                                   axis=1)[:, 0]
-        off = wp % page_tokens
-        x = L.embed_tokens(params["embed"], tok, cd)
-        cos, sin = _rope_tables(cfg, p_t)
+    def body(h, xs):
+        lp, pool = xs
+        xin = L.apply_norm(cfg, h[:, None], lp["ln1"])[:, 0]
+        p = lp["attn"]
+        if cfg.mla is not None:
+            m = cfg.mla
+            qk_dim = m.qk_nope_dim + m.qk_rope_dim
+            cq = L.rmsnorm(xin @ p["w_dq"].astype(cd), p["q_norm"],
+                           cfg.norm_eps)
+            q = (cq @ p["w_uq"].astype(cd)).reshape(n, hq, qk_dim)
+            q_nope = q[..., : m.qk_nope_dim]
+            q_rope = L.apply_rope(q[..., m.qk_nope_dim:][:, None],
+                                  cos[:, None], sin[:, None])[:, 0]
+            dkv = xin @ p["w_dkv"].astype(cd)
+            ckv_new = L.rmsnorm(dkv[..., : m.kv_lora_rank], p["kv_norm"],
+                                cfg.norm_eps)
+            krope_new = L.apply_rope(dkv[:, None, None, m.kv_lora_rank:],
+                                     cos[:, None], sin[:, None])[:, 0, 0]
+            upd_ckv, ckv_c = pool_write_read(pool, "ckv", ckv_new)
+            upd_kr, kr_c = pool_write_read(pool, "k_rope", krope_new)
+            w_uk = p["w_uk"].astype(cd).reshape(
+                m.kv_lora_rank, hq, m.qk_nope_dim)
+            # a lane's T rows of heads side by side: (B, T*H, .)
+            q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, w_uk).reshape(
+                b, t * hq, m.kv_lora_rank)
+            s = jnp.einsum("bhr,bsr->bhs", q_abs, ckv_c.astype(cd))
+            s = s + jnp.einsum("bhp,bsp->bhs",
+                               q_rope.reshape(b, t * hq, m.qk_rope_dim),
+                               kr_c.astype(cd))
+            s = (s * (qk_dim ** -0.5)).astype(jnp.float32)
+            last = jnp.repeat(p_bt, hq, axis=1)[:, :, None]      # (B, T*H, 1)
+            s = jnp.where(jnp.arange(s_pad)[None, None, :] <= last, s,
+                          L._mask_value(s.dtype))
+            probs = jax.nn.softmax(s, axis=-1)
+            ctx = jnp.einsum("bhs,bsr->bhr", probs.astype(cd),
+                             ckv_c.astype(cd)).reshape(n, hq, m.kv_lora_rank)
+            w_uv = p["w_uv"].astype(cd).reshape(
+                m.kv_lora_rank, hq, m.v_head_dim)
+            attn_out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv).reshape(
+                n, hq * m.v_head_dim)
+            attn_out = attn_out @ p["wo"].astype(cd)
+            new_pool = {**upd_ckv, **upd_kr}
+        else:
+            q = (xin @ p["wq"].astype(cd)).reshape(n, hq, dh)
+            knew = (xin @ p["wk"].astype(cd)).reshape(
+                n, cfg.padded_kv_heads, dh)
+            vnew = (xin @ p["wv"].astype(cd)).reshape(
+                n, cfg.padded_kv_heads, dh)
+            if cfg.rope_theta > 0:
+                q = L.apply_rope(q[:, None], cos[:, None], sin[:, None])[:, 0]
+                knew = L.apply_rope(knew[:, None], cos[:, None],
+                                    sin[:, None])[:, 0]
+            upd_k, kc = pool_write_read(pool, "k", knew)
+            upd_v, vc = pool_write_read(pool, "v", vnew)
+            attn_out = L.decode_attention(
+                q.reshape(b, t, hq, dh), kc, vc, p_bt + 1).reshape(n, hq * dh)
+            attn_out = attn_out.astype(cd) @ p["wo"].astype(cd)
+            new_pool = {**upd_k, **upd_v}
+        h = h + attn_out.astype(h.dtype)
+        xff = L.apply_norm(cfg, h[:, None], lp["ln2"])[:, 0]
+        h = h + ffn_block(lp["ffn"], xff[:, None], cfg)[:, 0]
+        return h, new_pool
 
-        def body(h, xs):
-            lp, pool = xs
-            xin = L.apply_norm(cfg, h[:, None], lp["ln1"])[:, 0]
-            p = lp["attn"]
-            if cfg.mla is not None:
-                m = cfg.mla
-                qk_dim = m.qk_nope_dim + m.qk_rope_dim
-                cq = L.rmsnorm(xin @ p["w_dq"].astype(cd), p["q_norm"],
-                               cfg.norm_eps)
-                q = (cq @ p["w_uq"].astype(cd)).reshape(b, hq, qk_dim)
-                q_nope = q[..., : m.qk_nope_dim]
-                q_rope = L.apply_rope(q[..., m.qk_nope_dim:][:, None],
-                                      cos[:, None], sin[:, None])[:, 0]
-                dkv = xin @ p["w_dkv"].astype(cd)
-                ckv_new = L.rmsnorm(dkv[..., : m.kv_lora_rank], p["kv_norm"],
-                                    cfg.norm_eps)
-                krope_new = L.apply_rope(dkv[:, None, None, m.kv_lora_rank:],
-                                         cos[:, None], sin[:, None])[:, 0, 0]
-                upd_ckv, ckv_c = pool_write_read(
-                    pool, "ckv", ckv_new, phys, off)
-                upd_kr, kr_c = pool_write_read(
-                    pool, "k_rope", krope_new, phys, off)
-                w_uk = p["w_uk"].astype(cd).reshape(
-                    m.kv_lora_rank, hq, m.qk_nope_dim)
-                q_abs = jnp.einsum("bhn,rhn->bhr", q_nope, w_uk)
-                s = jnp.einsum("bhr,bsr->bhs", q_abs, ckv_c.astype(cd))
-                s = s + jnp.einsum("bhp,bsp->bhs", q_rope, kr_c.astype(cd))
-                s = (s * (qk_dim ** -0.5)).astype(jnp.float32)
-                mask = jnp.arange(s_pad)[None, None, :] <= p_t[:, None, None]
-                s = jnp.where(mask, s, L._mask_value(s.dtype))
-                probs = jax.nn.softmax(s, axis=-1)
-                ctx = jnp.einsum("bhs,bsr->bhr", probs.astype(cd),
-                                 ckv_c.astype(cd))
-                w_uv = p["w_uv"].astype(cd).reshape(
-                    m.kv_lora_rank, hq, m.v_head_dim)
-                attn_out = jnp.einsum("bhr,rhv->bhv", ctx, w_uv).reshape(
-                    b, hq * m.v_head_dim)
-                attn_out = attn_out @ p["wo"].astype(cd)
-                new_pool = {**upd_ckv, **upd_kr}
-            else:
-                q = (xin @ p["wq"].astype(cd)).reshape(b, hq, dh)
-                knew = (xin @ p["wk"].astype(cd)).reshape(
-                    b, cfg.padded_kv_heads, dh)
-                vnew = (xin @ p["wv"].astype(cd)).reshape(
-                    b, cfg.padded_kv_heads, dh)
-                if cfg.rope_theta > 0:
-                    q = L.apply_rope(q[:, None], cos[:, None],
-                                     sin[:, None])[:, 0]
-                    knew = L.apply_rope(knew[:, None], cos[:, None],
-                                        sin[:, None])[:, 0]
-                upd_k, kc = pool_write_read(pool, "k", knew, phys, off)
-                upd_v, vc = pool_write_read(pool, "v", vnew, phys, off)
-                attn_out = L.decode_attention(q, kc, vc, p_t + 1).reshape(
-                    b, hq * dh)
-                attn_out = attn_out.astype(cd) @ p["wo"].astype(cd)
-                new_pool = {**upd_k, **upd_v}
-            h = h + attn_out.astype(h.dtype)
-            xff = L.apply_norm(cfg, h[:, None], lp["ln2"])[:, 0]
-            h = h + ffn_block(lp["ffn"], xff[:, None], cfg)[:, 0]
-            return h, new_pool
-
-        x2, new_pools = jax.lax.scan(body, x, (params["layers"], pools),
-                                     unroll=cfg.scan_unroll)
-        x2 = L.apply_norm(cfg, x2[:, None], params["ln_f"])[:, 0]
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embed"].T
-        logits = L.lm_logits(x2[:, None], head, cfg.vocab_size, cd)[:, 0]
-        return new_pools, logits.argmax(axis=-1).astype(jnp.int32)
-
-    pools, outs = jax.lax.scan(
-        one_token, pools,
-        (tokens.T, jnp.arange(t_total, dtype=jnp.int32)))
-    return outs.T, pools
+    x, pools = jax.lax.scan(body, x, (params["layers"], pools),
+                            unroll=cfg.scan_unroll)
+    x = L.apply_norm(cfg, x[:, None], params["ln_f"])[:, 0]
+    head = params.get("lm_head")
+    if head is None:
+        head = params["embed"].T
+    logits = L.lm_logits(x[:, None], head, cfg.vocab_size, cd)[:, 0]
+    return logits.argmax(axis=-1).astype(jnp.int32).reshape(b, t), pools
 
 
 # ---------------------------------------------------------------------- #

@@ -12,8 +12,8 @@ span / event            where
                         args: kind fresh|resume, and for fresh: tokens
                         prefilled, saved from the prefix cache)
 ``prefix_match``        radix-tree lookup at admission
-``call_prefill``        one T=8 paged-step call of an admission prefill
-                        (dispatch only)
+``call_prefill``        one ``PREFILL_CHUNK``-row paged-step call of an
+                        admission prefill (dispatch only)
 ``step``                one scheduler decode step (args: active, emitted),
                         tiled by its phases in the paged scheduler:
 ``schedule``            step phase: ``_schedule()``, the admissions

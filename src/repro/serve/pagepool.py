@@ -19,8 +19,11 @@ chain digest here; every stream admitted with that prefix points its
 table at the *same* physical slot and bumps its refcount.  A page is
 freed when no table row and no digest binding references it.
 
-Physical slot 0 is reserved as the *trash page*: inactive scheduler
-lanes point their whole table at it, so their (discarded) writes can
+Physical slot :data:`TRASH_PAGE` (0) is reserved as the *trash page*,
+which the paged step (``models/transformer.py``) defines: inactive
+scheduler lanes point their whole table at it, and the step sends the
+rows it must discard there (an admission-prefill chunk's pad rows, fed
+``PAD_TOKEN``, and any row past a lane's last slot), so discarded writes
 never land in a live stream's pages.
 """
 
@@ -34,8 +37,7 @@ import numpy as np
 
 from repro.memory.codecs import SCALE_SUFFIX, int8_dequantize, int8_quantize
 from repro.memory.tiers import CapacityError
-
-TRASH_PAGE = 0
+from repro.models.transformer import TRASH_PAGE
 
 
 class DevicePagePool:

@@ -57,12 +57,18 @@ from repro.memory.codecs import CodecRule, make_codec
 from repro.memory.stack import KeyClass
 from repro.memory.tiers import CapacityError
 from repro.models.registry import ModelApi
+from repro.models.transformer import PAD_TOKEN
 from repro.obs.metrics import Registry, StatsView
 from repro.obs.trace import Tracer, default_tracer
 from repro.serve.kvpage import KVPager
 from repro.serve.prefix import PrefixCache
 
-PREFILL_BUCKET = 8  # prompt-suffix pad granularity (compilations per bucket)
+# the contiguous ServeScheduler's prompt-suffix pad granularity
+# (compilations per bucket); the paged scheduler chunks by PREFILL_CHUNK
+PREFILL_BUCKET = 8
+# rows of one paged admission-prefill call: each call reads the weights
+# once for up to this many prompt tokens of one stream
+PREFILL_CHUNK = 128
 
 
 def make_slot_serve_step(cfg: ArchConfig, model: ModelApi) -> Callable:
@@ -233,7 +239,7 @@ class ServeScheduler:
             "steps": 0, "joined": 0, "queue_wait_s": 0.0,
             "parked": 0, "resumed": 0,
             "finished": 0, "park_failures": 0, "max_resident": 0,
-            "prefill_calls": 0, "prefill_tokens": 0,
+            "prefill_calls": 0, "prefill_tokens": 0, "prefill_pad_tokens": 0,
             "prefix_hits": 0, "prefill_tokens_saved": 0,
         })
 
@@ -293,6 +299,7 @@ class ServeScheduler:
         buf[:n] = tokens[t0:t1]
         self.stats["prefill_calls"] += 1
         self.stats["prefill_tokens"] += n
+        self.stats["prefill_pad_tokens"] += pad - n
         return self._prefill_fn(self.params, lane, jnp.asarray(buf),
                                 np.int32(t0), np.int32(n))
 
@@ -794,9 +801,13 @@ class PagedServeScheduler(ServeScheduler):
       .NGramProposer` — verified in ONE jitted call through the paged
       kernel's multi-row capability.  The accepted prefix commits with
       the same refcount/dirty-skip semantics as single-token decode, and
-      because ``paged_decode_step`` reproduces ``decode_step``'s exact
-      per-token computation graph, the emitted sequence is bit-identical
-      to single-token greedy decode for any ``spec_k``.
+      because each row of ``paged_decode_step`` computes what a
+      single-token step at its position computes, the emitted sequence
+      is single-token greedy decode for any ``spec_k``, up to last-bit
+      matmul rounding at a near-tie (``paged_decode_step``'s exactness
+      contract);
+    * a joining stream's prompt is prefilled through the same step in
+      :data:`PREFILL_CHUNK`-token chunks, one weight pass per chunk.
 
     Inactive slots point their whole table at the pool's trash page, so
     their discarded writes can never land in a live stream's KV.
@@ -887,15 +898,17 @@ class PagedServeScheduler(ServeScheduler):
     def _paged_prefill(self, table: List[int], tokens: List[int],
                        t0: int, t1: int) -> None:
         """Consume ``tokens[t0:t1]`` through the paged step in
-        :data:`PREFILL_BUCKET`-token chunks (one fixed-shape compile).
-        Chunk padding writes garbage KV past ``t1`` — always into this
-        stream's own pages at positions beyond its committed length, so
-        it is never attended and is overwritten by later real writes."""
+        :data:`PREFILL_CHUNK`-token chunks at batch 1 (one fixed-shape
+        compile): each call reads the weights once for the whole chunk,
+        layer by layer.  ``t0`` need not fall on a chunk or page
+        boundary.  The last chunk's pad rows carry ``PAD_TOKEN``, which
+        the step routes to the trash page, so they never touch this
+        stream's pages (``stats["prefill_pad_tokens"]`` counts them)."""
         tables = jnp.asarray(np.asarray(table, np.int32)[None])
         i = t0
         while i < t1:
-            m = min(PREFILL_BUCKET, t1 - i)
-            buf = np.zeros((1, PREFILL_BUCKET), np.int32)
+            m = min(PREFILL_CHUNK, t1 - i)
+            buf = np.full((1, PREFILL_CHUNK), PAD_TOKEN, np.int32)
             buf[0, :m] = tokens[i:i + m]
             with self.tracer.span("call_prefill"):
                 _, self.pool.leaves = self._paged_fn(
@@ -903,6 +916,7 @@ class PagedServeScheduler(ServeScheduler):
                     jnp.asarray([i], np.int32), jnp.asarray(buf))
             self.stats["prefill_calls"] += 1
             self.stats["prefill_tokens"] += m
+            self.stats["prefill_pad_tokens"] += PREFILL_CHUNK - m
             i += m
 
     def _admit_fresh(self, s: DecodeStream) -> Tuple[List[int], int]:

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.serve.scheduler import PREFILL_CHUNK
+
 HBM_BYTES = 16 * 10**9      # one v5e chip
 
 
@@ -88,7 +90,8 @@ def _xor(sh):
 def _paged_step(tokens):
     def lower(sh):
         """phi3's paged decode step at published widths, cut to 2 layers,
-        bf16 weights, the pool geometry of chip_smoke.py."""
+        bf16 weights, the pool geometry of chip_smoke.py: the T=1 step
+        over 4 lanes, or one admission-prefill chunk at batch 1."""
         from repro.configs import get_config
         from repro.models.registry import get_model
 
@@ -117,7 +120,7 @@ CASES = {
     "flash_attention_pallas-phi3": _flash,
     "xor_reduce_pallas": _xor,
     "paged_decode_step-phi3-T1": _paged_step(1),
-    "paged_decode_step-phi3-T8": _paged_step(8),
+    "paged_decode_step-phi3-T128": _paged_step(PREFILL_CHUNK),
 }
 
 

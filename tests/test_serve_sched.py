@@ -327,8 +327,9 @@ def test_paged_step_phases_tile_the_step_and_count_queue_wait(served_model):
     sched.step()
     spans = [r for r in tr.records() if r["ph"] == "X"]
     # the ring holds spans in the order they end
+    # 9 prompt tokens prefill in one PREFILL_CHUNK call
     assert [r["name"] for r in spans] == [
-        "call_prefill", "call_prefill", "admit", "schedule", "feed",
+        "call_prefill", "admit", "schedule", "feed",
         "call_decode", "wait", "commit", "step"]
     by = {r["name"]: r for r in spans}
     assert _holds(by["step"], by["schedule"])
