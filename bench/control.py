@@ -39,11 +39,9 @@ def readings(workload: str, seed: int, window: str) -> dict:
     spec, cell, cfg, mix = run.load_cell(workload)
     enable_cache()
     with open(window, "rb") as f:
-        w, _ = pickle.load(f)
-    lim = correct.load_limits(cell["name"])
+        w = pickle.load(f)
+    _, picked, _ = run.checked_sample(cell, w, seed)
     done = [s for s in w.streams if len(s.tokens) >= s.req.max_new]
-    picked = correct.sample([(s.req.prompt, s.tokens) for s in done], seed,
-                            int(lim["sample"]))
     got = correct.read_gaps(cfg, local.model_seed(seed), picked,
                             cfg["serving"]["max_len"], control=True)
     return dict(got, seed=seed, requests=len(picked), finished=len(done),

@@ -11,6 +11,8 @@ program reads a gap near zero: a bf16 near-tie at most.
 The control (``read_gaps(..., control=True)``) reads the same sequences
 through the reference computed in float8: at each position the gap of
 the token the float8 logits put first.
+
+The reference is the configuration's family's (``harness/family.py``).
 """
 
 from __future__ import annotations
@@ -24,8 +26,8 @@ from typing import Dict, List, Sequence, Tuple
 
 import numpy as np
 
-from harness import reference
-from harness.traffic import STREAM_SAMPLE, rng
+from harness import family
+from harness.traffic import STREAM_MIGRATED, STREAM_SAMPLE, rng
 
 LIMITS = Path(__file__).resolve().parents[1] / "limits"
 
@@ -39,14 +41,20 @@ def load_limits(cell: str) -> Dict:
 
 
 def sample(finished: Sequence[Tuple[List[int], List[int]]], seed: int,
-           n: int) -> List[Tuple[List[int], List[int]]]:
+           n: int, migrated: Sequence[int] = (), n_migrated: int = 0,
+           ) -> List[Tuple[List[int], List[int]]]:
     """``n`` of the finished (prompt, served) pairs: the one with the most
-    served tokens, and the rest drawn from the seed."""
+    served tokens, then ``n_migrated`` of those whose indices are in
+    ``migrated`` (streams moved off a lost worker), and the rest, each
+    drawn from the seed."""
     if not finished:
         return []
     longest = min(range(len(finished)),
                   key=lambda i: (-len(finished[i][1]), i))
     keep = [longest]
+    moved = [i for i in migrated if i not in keep]
+    pick = rng(seed, STREAM_MIGRATED).permutation(len(moved))
+    keep += sorted(moved[i] for i in pick[:n_migrated])
     rest = [i for i in range(len(finished)) if i not in keep]
     pick = rng(seed, STREAM_SAMPLE).permutation(len(rest))
     keep += sorted(rest[i] for i in pick[:max(n - len(keep), 0)])
@@ -62,6 +70,7 @@ def read_gaps(cfg: Dict, seed: int,
     import jax
     import jax.numpy as jnp
 
+    reference = family.module("reference", cfg)
     t0 = time.perf_counter()
     weights = reference.make_weights(cfg, seed)
     seqs = [list(p) + list(s[:-1]) for p, s in picked]

@@ -6,10 +6,13 @@ The timed entry is the program's own: ``LocalServe.step`` ->
 ``paged_decode_step`` (``_paged_fn``).  The harness calls that jit
 unchanged; it only encloses each call in a host span that says which of
 its two shapes it is (``bench.call_decode``, the T=1 step over every
-slot, or ``bench.call_prefill``, a T=8 prefill chunk at B=1), from which
-the trace reduction names the device's runs of the program.
-``_paged_prefill`` is wrapped to record which positions each admission
-prefilled.
+slot, or ``bench.call_prefill``, one ``PREFILL_CHUNK``-row (128) chunk of
+an admission's prompt at B=1), from which the trace reduction names the
+device's runs of the program.  ``_paged_prefill`` is wrapped to record
+which positions each admission prefilled.
+
+This is the ``local`` driver: a configuration whose ``serving`` block
+names no ``driver`` runs here (``bench/run.py``).
 """
 
 from __future__ import annotations
@@ -24,21 +27,27 @@ import time
 from typing import Dict, List, Optional, Tuple
 
 from harness import tracefile
-from harness.device import CompileClock
+from harness.device import CompileClock, stamp
 from harness.traffic import Generator, Request, warmup_rounds
 
-ARCH_KEYS = ("name", "family", "n_layers", "d_model", "n_heads", "n_kv_heads",
-             "d_ff", "vocab_size", "head_dim", "norm", "act", "rope_theta",
-             "tie_embeddings", "norm_eps", "param_dtype", "compute_dtype")
 # the profiler trace of a ``--trace 1`` run: from this share of the window
 # on, for this many seconds (longer until it holds an admission)
 TRACE_AT, TRACE_SECONDS = 0.4, 4.0
 
 
 def arch_of(cfg: Dict):
-    from repro.configs.base import ArchConfig
+    """The program's ``ArchConfig`` of ``cfg``: every field of it that the
+    file gives, the ``mla`` and ``moe`` blocks as their spec types."""
+    from repro.configs.base import ArchConfig, MLASpec, MoESpec
 
-    return ArchConfig(**{k: cfg[k] for k in ARCH_KEYS if k in cfg})
+    specs = {"mla": MLASpec, "moe": MoESpec}
+    kw = {}
+    for f in dataclasses.fields(ArchConfig):
+        if f.name in cfg:
+            v = cfg[f.name]
+            kw[f.name] = (specs[f.name](**v) if f.name in specs
+                          and v is not None else v)
+    return ArchConfig(**kw)
 
 
 def model_seed(seed: int) -> int:
@@ -60,6 +69,8 @@ class Stream:
     due: float
     times: List[float] = dataclasses.field(default_factory=list)
     tokens: List[int] = dataclasses.field(default_factory=list)
+    # moved off a lost worker and resumed on another (the fleet driver)
+    migrated: bool = False
 
     @property
     def plen(self) -> int:
@@ -79,6 +90,7 @@ class Window:
     trace_steps: Tuple[int, int] = (0, 0)
     trace_prefills: Tuple[int, int] = (0, 0)
     peak_bytes: int = 0
+    device: Optional[Dict] = None          # as the serving processes see it
 
 
 def module_name(fn) -> str:
@@ -296,13 +308,16 @@ def closed_loop(srv, mix: Dict, seed: int, seconds: float,
 
 
 def run_window(cfg: Dict, mix: Dict, seed: int, seconds: float,
-               trace: bool, on_built=None) -> Window:
-    """Set up, measure, read the peak, and close the stack.  ``on_built``
-    (tests only) is called with the built stack before warm-up."""
+               trace: bool, chips: Optional[int] = None,
+               on_built=None) -> Window:
+    """Check the device (``stamp(chips)``), set up, measure, read the
+    peak, and close the stack.  ``on_built`` (tests only) is called with
+    the built stack before warm-up."""
     import jax
 
     from harness.device import peak_bytes
 
+    device = stamp(chips)
     t = time.perf_counter()
     with CompileClock() as clock:
         srv = build(cfg, seed, trace)
@@ -324,7 +339,7 @@ def run_window(cfg: Dict, mix: Dict, seed: int, seconds: float,
             w = closed_loop(srv, mix, seed, seconds, tdir)
             if tdir is not None and w.trace_steps[1] > w.trace_steps[0]:
                 w.trace = tracefile.extract(tdir, module)
-        w.peak_bytes = peak_bytes()
+        w.peak_bytes, w.device = peak_bytes(), device
     finally:
         srv.close()
     return w

@@ -20,7 +20,7 @@ from typing import Dict, List, Optional
 import numpy as np
 
 import flops
-from harness import tracefile
+from harness import family, tracefile
 
 
 class Context:
@@ -33,6 +33,11 @@ class Context:
     @property
     def window_s(self) -> float:
         return self.window.t1 - self.window.t0
+
+    @functools.cached_property
+    def flops(self):
+        """The operation and byte counts of the configuration's family."""
+        return family.module("flops", self.cfg)
 
     @functools.cached_property
     def peaks(self) -> Dict[str, float]:
@@ -71,11 +76,11 @@ class Context:
         tokens (tokens the prefix cache served are not computed and do
         not count)."""
         a, b = self.window.trace_steps
-        total = sum(flops.decode_flops(self.cfg, pos)
+        total = sum(self.flops.decode_flops(self.cfg, pos)
                     for pos in self.window.steps[a:b])
         a, b = self.window.trace_prefills
         for t0, t1 in self.window.prefills[a:b]:
-            total += sum(flops.flops_per_token(self.cfg, p)
+            total += sum(self.flops.flops_per_token(self.cfg, p)
                          for p in range(t0, t1))
         return total
 

@@ -13,6 +13,17 @@ and the p99 of a window is read from its few largest admissions.
 A length is lognormal: ``{"median": m, "sigma": s, "min": lo, "max": hi}``
 gives ``clip(m * exp(s * z_q), lo, hi)`` at the quantile ``q`` of the
 standard normal.
+
+A mix with ``"system": {"tokens": n}`` puts one system prompt of ``n``
+tokens, drawn once from the seed, in front of every request's own
+prompt (``prompt`` is then the user's part).  A mix with ``"rate_per_s"``
+is an open loop: its requests are sent at arrival times, whatever is
+still running.  The gaps between arrivals are a Poisson process's at
+that rate, stratified: each block of ``arrival_strata`` gaps holds the
+exponential's quantiles at (i + 0.5) / k, scaled so that their mean is
+exactly ``1 / rate_per_s``, in an order drawn from the seed.  Every
+seed offers the same load over each block, and the order gives a
+Poisson stream's bursts, which differ from seed to seed.
 """
 
 from __future__ import annotations
@@ -26,6 +37,7 @@ import numpy as np
 
 # independent numpy streams under one seed
 STREAM_TOKENS, STREAM_WARMUP, STREAM_SAMPLE = 1, 3, 4
+STREAM_SYSTEM, STREAM_MIGRATED, STREAM_ARRIVALS = 5, 6, 7
 
 
 @dataclasses.dataclass
@@ -72,6 +84,9 @@ class Generator:
         self._output_q = quantile_lengths(mix["output"], self.strata)
         self._block: List = []
         self._n = 0
+        sysp = mix.get("system")
+        self.system = (rng(seed, STREAM_SYSTEM).integers(
+            0, self.vocab, size=int(sysp["tokens"])).tolist() if sysp else [])
 
     def _tokens(self, n: int) -> List[int]:
         return self._tok.integers(0, self.vocab, size=n).tolist()
@@ -87,12 +102,31 @@ class Generator:
 
     def next(self) -> Request:
         plen, max_new = self._next_sizes()
-        req = Request(self._n, self._tokens(plen), max_new)
+        req = Request(self._n, self.system + self._tokens(plen), max_new)
         self._n += 1
         return req
 
     def take(self, n: int) -> List[Request]:
         return [self.next() for _ in range(n)]
+
+
+class Arrivals:
+    """The gaps between an open loop's arrivals under one seed, in
+    seconds, in the order they are sent."""
+
+    def __init__(self, mix: Dict, seed: int):
+        k = int(mix.get("arrival_strata", 64))
+        gaps = [-math.log(1.0 - (i + 0.5) / k) for i in range(k)]
+        scale = k / (float(mix["rate_per_s"]) * sum(gaps))
+        self._gaps = [g * scale for g in gaps]
+        self._order = rng(seed, STREAM_ARRIVALS)
+        self._block: List[float] = []
+
+    def next(self) -> float:
+        if not self._block:
+            perm = self._order.permutation(len(self._gaps))
+            self._block = [self._gaps[i] for i in perm][::-1]
+        return self._block.pop()
 
 
 def warmup_rounds(mix: Dict, seed: int, vocab: int) -> List[List[Request]]:

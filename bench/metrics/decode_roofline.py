@@ -1,12 +1,11 @@
 """Share of its roofline that the T=1 decode step reaches: the least
 time the chip could take for the traced steps (the larger of their bytes
 over peak bandwidth and their operations over peak FLOP/s, from
-``bench/flops.py`` at the configuration's logical sizes) over the device
-time of those steps.  The decode step is bound by bandwidth."""
+the family's counts, ``bench/flops.py`` for the dense family, at the
+configuration's logical sizes) over the device time of those steps.  The
+decode step is bound by bandwidth."""
 
 import sys
-
-import flops
 
 
 def read(ctx):
@@ -18,8 +17,9 @@ def read(ctx):
     # steps with no active lane send nothing to the device
     n = min(len(steps), p["calls"])
     n_steps = len(steps)
-    need_bytes = sum(flops.decode_bytes(ctx.cfg, s) for s in steps) / n_steps
-    need_flops = sum(flops.decode_flops(ctx.cfg, s) for s in steps) / n_steps
+    count = ctx.flops
+    need_bytes = sum(count.decode_bytes(ctx.cfg, s) for s in steps) / n_steps
+    need_flops = sum(count.decode_flops(ctx.cfg, s) for s in steps) / n_steps
     t_bytes = need_bytes / ctx.peaks["hbm_bytes_per_s"]
     t_flops = need_flops / ctx.peaks["bf16_flops"]
     bound = "bandwidth" if t_bytes >= t_flops else "compute"

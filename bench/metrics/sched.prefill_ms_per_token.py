@@ -1,6 +1,7 @@
-"""Device time of the T=8 prefill chunks in the trace (the
-step program's runs that the harness called at T=8) per prompt token
-prefilled in the traced interval."""
+"""Device time of the admission prefill chunks in the trace (the step
+program's runs that the harness called at one chunk of
+``PREFILL_CHUNK`` = 128 rows, B=1) per prompt token prefilled in the
+traced interval."""
 
 
 def read(ctx):

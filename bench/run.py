@@ -7,7 +7,10 @@ Run from the root of a checkout.  ``BENCHMARK.json`` names the cell's
 configuration (``bench/configs/<config>.json``), traffic mix
 (``bench/traffic/<mix>.json``) and metrics; each per-layer metric is read
 by ``bench/metrics/<metric>.py``; the limit of the correctness check is
-in ``bench/limits/<cell>.json``.  Nothing here names a cell.
+in ``bench/limits/<cell>.json``.  The configuration's ``serving.driver``
+names the module that serves the cell, ``bench/harness/<driver>.py``
+(``local`` where it names none); its family picks the reference
+(``bench/harness/family.py``).  Nothing here names a cell.
 
 Set-up (weights from the seed, the warm-up of every shape the window
 uses) is timed as ``setup_s``, from this process's start; then the cell's
@@ -49,6 +52,20 @@ def say(msg: str) -> None:
     print(msg, file=sys.stderr, flush=True)
 
 
+def driver(cfg: dict):
+    """The module that serves ``cfg``: ``harness/<serving.driver>.py``.
+    Its ``run_window(cfg, mix, seed, seconds, trace, chips=None,
+    on_built=None)`` checks that the processes it serves from hold a TPU
+    and ``chips`` chips (raising ``NoAccelerator``), and returns the
+    window's record with ``device`` as they report it."""
+    import importlib
+
+    name = cfg["serving"].get("driver", "local")
+    if not (BENCH / "harness" / f"{name}.py").is_file():
+        raise SystemExit(f"no driver bench/harness/{name}.py")
+    return importlib.import_module(f"harness.{name}")
+
+
 def load_cell(name: str):
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     cells = {w["name"]: w for w in spec["workloads"]}
@@ -62,17 +79,31 @@ def load_cell(name: str):
     return spec, cell, cfg, mix
 
 
-def serve(cell: dict, cfg: dict, mix: dict, seed: int, seconds: float,
-          trace: bool, device, on_built=None):
-    """Set-up and the measured window; returns the window's record and
-    the device."""
-    from harness import local
-
-    w = local.run_window(cfg, mix, seed, seconds, trace, on_built=on_built)
+def serve(cfg: dict, mix: dict, seed: int, seconds: float, trace: bool,
+          chips=None, on_built=None):
+    """Set-up and the measured window; returns the window's record.
+    ``chips`` ``None`` (the tests on the CPU) looks for no TPU."""
+    w = driver(cfg).run_window(cfg, mix, seed, seconds, trace, chips=chips,
+                               on_built=on_built)
     say(f"window {w.t1 - w.t0:.3f} s; {len(w.streams)} requests sent, "
         f"{sum(len(s.tokens) for s in w.streams)} tokens; "
         f"compilations inside the window: {w.compiles}")
-    return w, device
+    return w
+
+
+def checked_sample(cell: dict, w, seed: int):
+    """The cell's limits, the finished requests the check reads (the
+    longest, those moved off a lost worker as the limits ask, the rest
+    from the seed), and how many moved ones the sample lacks."""
+    from harness import correct
+
+    lim = correct.load_limits(cell["name"])
+    done = [s for s in w.streams if len(s.tokens) >= s.req.max_new]
+    moved = [i for i, s in enumerate(done) if s.migrated]
+    n_moved = int(lim.get("migrated", 0))
+    picked = correct.sample([(s.req.prompt, s.tokens) for s in done], seed,
+                            int(lim["sample"]), moved, n_moved)
+    return lim, picked, max(n_moved - len(moved), 0)
 
 
 def report(cell: dict, cfg: dict, mix: dict, spec: dict, seed: int,
@@ -82,10 +113,7 @@ def report(cell: dict, cfg: dict, mix: dict, spec: dict, seed: int,
 
     setup_s = w.t0 - t_start
     say(f"set-up {setup_s:.3f} s")
-    lim = correct.load_limits(cell["name"])
-    done = [s for s in w.streams if len(s.tokens) >= s.req.max_new]
-    picked = correct.sample([(s.req.prompt, s.tokens) for s in done], seed,
-                            int(lim["sample"]))
+    lim, picked, short = checked_sample(cell, w, seed)
     t = time.perf_counter()
     got = correct.read_gaps(cfg, local.model_seed(seed), picked,
                             cfg["serving"]["max_len"])
@@ -93,6 +121,10 @@ def report(cell: dict, cfg: dict, mix: dict, spec: dict, seed: int,
                             "limit": lim["logit_gap"]}}
     ok = bool(picked) and all(c["value"] <= c["limit"]
                               for c in checks.values())
+    if "migrated" in lim:
+        # a cell that loses a worker checks streams that were moved off it
+        checks["migrated_short"] = {"value": short, "limit": 0}
+        ok = ok and not short
     say(f"check: {len(picked)} requests, {got['tokens']} served tokens "
         f"against the float32 reference in {time.perf_counter() - t:.1f} s")
     say(f"device: {device['platform']} {device['kind']} x{device['count']}")
@@ -119,10 +151,10 @@ def report(cell: dict, cfg: dict, mix: dict, spec: dict, seed: int,
 def run(cell: dict, cfg: dict, mix: dict, spec: dict, seed: int,
         seconds: float, trace: bool, device, t_start: float = T_START,
         on_built=None) -> dict:
-    """Window and check in this one process (the tests' way in; ``main``
-    puts the window in a process of its own)."""
-    w, device = serve(cell, cfg, mix, seed, seconds, trace, device,
-                      on_built=on_built)
+    """Window and check in this one process, with the device look skipped
+    and ``device`` reported (the tests' way in; ``main`` puts the window
+    in a process of its own)."""
+    w = serve(cfg, mix, seed, seconds, trace, on_built=on_built)
     return report(cell, cfg, mix, spec, seed, trace, device, w, t_start)
 
 
@@ -136,21 +168,20 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     spec, cell, cfg, mix = load_cell(args.workload)
     sys.path.insert(0, str(ROOT / "src"))
-    from harness.device import NoAccelerator, enable_cache, stamp
+    from harness.device import NoAccelerator, enable_cache
 
     if args.window_to:
         # the child: set-up and the window; the chips are free again for
         # the check once it has exited
         try:
-            device = stamp(cell["chips"])
             enable_cache()
-            w, device = serve(cell, cfg, mix, args.seed, args.seconds,
-                              bool(args.trace), device)
+            w = serve(cfg, mix, args.seed, args.seconds, bool(args.trace),
+                      chips=cell["chips"])
         except NoAccelerator as e:
             say(str(e))
             return 1
         with open(args.window_to, "wb") as f:
-            pickle.dump((w, device), f)
+            pickle.dump(w, f)
         return 0
     with tempfile.TemporaryDirectory(prefix="bench_run_") as tmp:
         out = os.path.join(tmp, "window.pkl")
@@ -161,10 +192,10 @@ def main(argv=None) -> int:
         if rc != 0:
             return rc
         with open(out, "rb") as f:
-            w, device = pickle.load(f)
+            w = pickle.load(f)
     enable_cache()
     result = report(cell, cfg, mix, spec, args.seed, bool(args.trace),
-                    device, w, T_START)
+                    w.device, w, T_START)
     print(json.dumps(result), flush=True)
     return 0
 

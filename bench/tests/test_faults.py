@@ -80,7 +80,7 @@ def test_control_reads_three_times_the_program():
 
     cell, cfg, mix = tiny.cell(), tiny.config(), tiny.mix()
     seed = 2 ** 31 + 91
-    w, _ = run.serve(cell, cfg, mix, seed, 6.0, False, tiny.DEVICE)
+    w = run.serve(cfg, mix, seed, 6.0, False)
     done = [(s.req.prompt, s.tokens) for s in w.streams
             if len(s.tokens) >= s.req.max_new]
     picked = correct.sample(done, seed, 6)
